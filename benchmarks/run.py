@@ -1,7 +1,13 @@
 """Benchmark orchestrator — one function per paper table/figure.
 
+CPU only: this process runs JAX work itself and starts children that run
+more, and a TPU chip belongs to one process at a time, so it holds JAX to
+the CPU (``JAX_PLATFORMS=cpu``) for itself and its children.  Its timings
+are XLA-CPU timings, not device numbers.  ``chip_smoke.py`` is what runs
+on the chip.
+
 Prints ``name,us_per_call,derived`` CSV rows.  Multi-device benches run in
-subprocesses (this process keeps 1 CPU device per repo policy).
+subprocesses on forced host devices (this process keeps 1 CPU device).
 
   PYTHONPATH=src python -m benchmarks.run [--quick]
 """
@@ -73,6 +79,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="skip the slower measured benches")
     args = ap.parse_args()
+    os.environ["JAX_PLATFORMS"] = "cpu"     # before any JAX import
 
     print("# table1 (paper Table 1)")
     table1_factorizations()

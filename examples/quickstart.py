@@ -1,9 +1,11 @@
 """Quickstart: the paper's factorized zero-copy all-to-all in 60 seconds.
 
-Runs on 12 virtual CPU devices: builds a 2x3x2 torus (Cartesian
-communicator), runs the d=3 round schedule, checks it against the direct
-collective, and shows the tuning model's algorithm choice — the three
-viewpoints of the paper in one script.
+A CPU demo: it forces 12 virtual host devices, so it does not run on a
+TPU host (``chip_smoke.py --chips 4`` is the chip's torus all-to-all).
+Builds a 2x3x2 torus (Cartesian communicator), runs the d=3 round
+schedule, checks it against the direct collective, and shows the tuning
+model's algorithm choice — the three viewpoints of the paper in one
+script.
 
   PYTHONPATH=src python examples/quickstart.py
 """

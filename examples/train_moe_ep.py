@@ -5,7 +5,9 @@ MoE layer executes the d=2 hierarchical schedule each step, forward and
 backward.
 
 Shows: sharded init, factorized-A2A MoE, fault-tolerant trainer with
-checkpointing, and loss decreasing on a learnable task.
+checkpointing, and loss decreasing on a learnable task.  A CPU demo: it
+forces 8 virtual host devices (``chip_smoke.py --chips 4`` trains
+phi3.5-moe over the chips of a TPU host).
 
   PYTHONPATH=src python examples/train_moe_ep.py [--steps 150]
 """

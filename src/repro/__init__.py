@@ -1,6 +1,4 @@
 """repro: factorized zero-copy all-to-all for multidimensional tori
 (Träff, CS.DC 2026) — JAX/TPU training & serving framework."""
 
-from . import compat  # noqa: F401  (installs JAX version shims)
-
 __version__ = "1.0.0"

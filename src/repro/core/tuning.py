@@ -27,6 +27,8 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
+import numpy as np
+
 from .dims import dims_create, max_dims, prime_factorization
 
 
@@ -67,6 +69,30 @@ DCN_AXES = ("pod",)
 def default_links(axis_names) -> tuple[LinkModel, ...]:
     """Per-axis link models: DCN for inter-pod axes, ICI otherwise."""
     return tuple(DCN if a in DCN_AXES else ICI for a in axis_names)
+
+
+def mesh_links(mesh, axis_names) -> tuple[LinkModel, ...] | None:
+    """Per-axis link models observed from the TPU devices of ``mesh``.
+
+    An axis along which the devices lie in more than one slice crosses
+    DCN; one that stays inside a slice is ICI, whatever its name (the
+    ``pod`` axis of the four-chip v5e host is ICI).  Devices of a
+    single-slice deployment carry no ``slice_index`` and count as one
+    slice, as in JAX's own mesh construction.  Returns ``None`` for other
+    platforms (CPU), so that callers keep the name-based
+    :func:`default_links`.
+    """
+    devs = mesh.devices
+    if any(d.platform != "tpu" for d in devs.flat):
+        return None
+    slices = np.vectorize(lambda d: getattr(d, "slice_index", 0),
+                          otypes=[int])(devs)
+    out = []
+    for a in axis_names:
+        ax = mesh.axis_names.index(a)
+        first = np.take(slices, [0], axis=ax)
+        out.append(DCN if (slices != first).any() else ICI)
+    return tuple(out)
 
 
 def resolve_links(links, dims, axis_names=None) -> tuple[LinkModel, ...]:

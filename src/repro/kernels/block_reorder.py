@@ -52,6 +52,26 @@ def _pack_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
+def _tile_copy(x, sigma_k, grid, in_map, out_map, interpret):
+    """Copy ``(p, B)`` rows tile by tile, one sigma_k-row tile per step.
+
+    ``x`` is viewed as ``(p // sigma_k, sigma_k, B)`` so that the last two
+    dimensions of each ``(1, sigma_k, B)`` block are the array's own: the
+    TPU's (8, 128) tiling rule then holds for any sigma_k, sigma_0 = 1
+    included."""
+    p, B = x.shape
+    tiles = x.reshape(p // sigma_k, sigma_k, B)
+    out = pl.pallas_call(
+        _pack_kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, sigma_k, B), in_map)],
+        out_specs=pl.BlockSpec((1, sigma_k, B), out_map),
+        out_shape=jax.ShapeDtypeStruct(tiles.shape, x.dtype),
+        interpret=interpret,
+    )(tiles)
+    return out.reshape(p, B)
+
+
 @functools.partial(jax.jit, static_argnames=("dims", "k", "interpret"))
 def datatype_pack(x, *, dims: tuple[int, ...], k: int,
                   interpret: bool = False):
@@ -62,7 +82,7 @@ def datatype_pack(x, *, dims: tuple[int, ...], k: int,
     order.  Equivalent to ``ref.ref_block_reorder`` with the round-k
     positions.
     """
-    p, B = x.shape
+    p = x.shape[0]
     d = len(dims)
     if math.prod(dims) != p:
         raise ValueError(f"prod(dims)={math.prod(dims)} != p={p}")
@@ -82,19 +102,12 @@ def datatype_pack(x, *, dims: tuple[int, ...], k: int,
 
     def in_map(j, u):
         base = _digits_to_tile(u, uppers_dims, uppers_strides)
-        return (base + j, 0)   # tile row (sigma_k rows), full width
+        return (base + j, 0, 0)   # one sigma_k-row tile, full width
 
     def out_map(j, u):
-        return (j * tiles_per_peer + u, 0)
+        return (j * tiles_per_peer + u, 0, 0)
 
-    return pl.pallas_call(
-        _pack_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((sigma_k, B), in_map)],
-        out_specs=pl.BlockSpec((sigma_k, B), out_map),
-        out_shape=jax.ShapeDtypeStruct((p, B), x.dtype),
-        interpret=interpret,
-    )(x)
+    return _tile_copy(x, sigma_k, grid, in_map, out_map, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "k", "interpret"))
@@ -102,7 +115,7 @@ def datatype_unpack(y, *, dims: tuple[int, ...], k: int,
                     interpret: bool = False):
     """Inverse of ``datatype_pack``: scatter contiguous composite messages
     back into datatype positions (the receive-side explicit copy)."""
-    p, B = y.shape
+    p = y.shape[0]
     d = len(dims)
     sig = strides(dims)
     sigma_k = sig[k]
@@ -116,17 +129,10 @@ def datatype_unpack(y, *, dims: tuple[int, ...], k: int,
     grid = (Dk, n_upper)
 
     def in_map(j, u):
-        return (j * tiles_per_peer + u, 0)
+        return (j * tiles_per_peer + u, 0, 0)
 
     def out_map(j, u):
         base = _digits_to_tile(u, uppers_dims, uppers_strides)
-        return (base + j, 0)
+        return (base + j, 0, 0)
 
-    return pl.pallas_call(
-        _pack_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((sigma_k, B), in_map)],
-        out_specs=pl.BlockSpec((sigma_k, B), out_map),
-        out_shape=jax.ShapeDtypeStruct((p, B), y.dtype),
-        interpret=interpret,
-    )(y)
+    return _tile_copy(y, sigma_k, grid, in_map, out_map, interpret)

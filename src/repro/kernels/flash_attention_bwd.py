@@ -63,12 +63,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         mask &= cols > rows - window
     s = jnp.where(mask, s, NEG_INF)
 
+    # running max / sum are (bq, 1) columns, the layout of the lse output
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] \
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha \
         + jnp.dot(p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -76,14 +77,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     def _finish():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, ...] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_ref[...] / safe).astype(o_ref.dtype)
         lse_ref[0, 0, ...] = m_ref[...] + jnp.log(safe)
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
                         block_q=128, block_k=128, kv_offset=0,
                         interpret=False):
-    """Returns (out, lse); lse: (B, Hq, Sq) f32."""
+    """Returns (out, lse); lse: (B, Hq, Sq, 1) f32.
+
+    The trailing size-1 axis keeps the last two dimensions of every lse
+    block (``(bq, 1)``) legal for the TPU's (8, 128) tiling rule."""
     B, Hq, Sq, Dh = q.shape
     _, Hkv, Skv, _ = k.shape
     group = Hq // Hkv
@@ -107,16 +111,16 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sq, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, Dh), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -129,16 +133,18 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
 
 def _bwd_tile(q, k, v, do, lse, delta, rows, cols, *, scale, causal, window,
               kv_len):
-    """Recompute p and ds for one (bq, bk) tile; returns (p, ds) f32."""
+    """Recompute p and ds for one (bq, bk) tile; returns (p, ds) f32.
+
+    ``lse`` and ``delta`` are (bq, 1) columns."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     mask = cols < kv_len
     if causal:
         mask &= cols <= rows
     if window is not None:
         mask &= cols > rows - window
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     return p, ds
 
 
@@ -213,12 +219,12 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
     bq = _pick_block(Sq, block_q)
     bk = _pick_block(Skv, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                               # (B, Hq, Sq)
+                    axis=-1, keepdims=True)                # (B, Hq, Sq, 1)
 
     common = dict(scale=scale, causal=causal, window=window, block_q=bq,
                   block_k=bk, kv_len=Skv, kv_offset=kv_offset)
     q_spec = pl.BlockSpec((1, 1, bq, Dh), lambda b, h, i, j: (b, h, i, 0))
-    qrow_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    qrow_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, Dh),
                            lambda b, h, i, j, g=group: (b, h // g, j, 0))
 
@@ -235,7 +241,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
 
     # dk/dv per *query* head (race-free); group-sum to KV heads after.
     q_spec2 = pl.BlockSpec((1, 1, bq, Dh), lambda b, h, j, i: (b, h, i, 0))
-    qrow2 = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
+    qrow2 = pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0))
     kv_spec2 = pl.BlockSpec((1, 1, bk, Dh),
                             lambda b, h, j, i, g=group: (b, h // g, j, 0))
     okv_spec = pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, i: (b, h, j, 0))
@@ -259,17 +265,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=None,
 # custom_vjp wrapper — the trainable flash attention
 # ---------------------------------------------------------------------------
 
-_NONDIFF = ("causal", "window", "scale", "block_q", "block_k", "kv_offset",
-            "interpret")
-try:        # modern API; older runtimes only know positional argnums
-    _vjp_deco = functools.partial(jax.custom_vjp, nondiff_argnames=_NONDIFF)
-    _vjp_deco(lambda q, k, v, **kw: q)
-except TypeError:
-    _vjp_deco = functools.partial(
-        jax.custom_vjp, nondiff_argnums=tuple(range(3, 3 + len(_NONDIFF))))
-
-
-@_vjp_deco
+@functools.partial(
+    jax.custom_vjp,
+    nondiff_argnames=("causal", "window", "scale", "block_q", "block_k",
+                      "kv_offset", "interpret"))
 def flash_attention_trainable(q, k, v, causal=True, window=None, scale=None,
                               block_q=128, block_k=128, kv_offset=0,
                               interpret=False):

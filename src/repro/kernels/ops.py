@@ -1,15 +1,16 @@
 """Public jit'd kernel wrappers and implementation dispatch.
 
 The model stack calls these entry points; each selects between the Pallas
-kernel (TPU target; interpret mode on CPU when forced) and the XLA
-reference path.  On this CPU-only container the default is the XLA path —
-Pallas kernels are validated in interpret mode by the test suite and meant
-to be enabled with ``impl="pallas"`` on real TPUs.
+kernel compiled for the TPU (``impl="pallas"``), the same kernel run by
+the Pallas interpreter (``impl="pallas_interpret"``, for CPU tests only)
+and the XLA reference path (``impl="xla"``).  No default selects the
+interpreter.  The Pallas kernels compile for a TPU v5e
+(``tests/test_tpu_compile.py``) and run there against ``ref.py``
+(``chip_smoke.py``).
 
-Training note: ``attention`` exposes a ``jax.custom_vjp`` whose forward
-may run the Pallas kernel while the backward uses the XLA reference
-gradient (same math, so gradients are exact for the function computed);
-a Pallas backward kernel is a tracked open item in ROADMAP.md.
+``attention(impl="pallas")`` is trainable: a ``jax.custom_vjp`` whose
+forward and backward are both Pallas kernels
+(``flash_attention_bwd.flash_attention_trainable``).
 """
 
 from __future__ import annotations
@@ -55,7 +56,7 @@ def expert_matmul(lhs, rhs, *, impl: AttentionImpl = "xla",
                           interpret=(impl == "pallas_interpret"))
 
 
-def pack_round(x, dims, k, *, impl: AttentionImpl = "pallas_interpret"):
+def pack_round(x, dims, k, *, impl: AttentionImpl = "pallas"):
     """Round-k datatype pack (explicit-copy baseline path)."""
     if impl == "xla":
         from repro.core.simulator import round_datatype
@@ -65,7 +66,7 @@ def pack_round(x, dims, k, *, impl: AttentionImpl = "pallas_interpret"):
                          interpret=(impl == "pallas_interpret"))
 
 
-def unpack_round(y, dims, k, *, impl: AttentionImpl = "pallas_interpret"):
+def unpack_round(y, dims, k, *, impl: AttentionImpl = "pallas"):
     if impl == "xla":
         from repro.core.simulator import round_datatype
         pos, extent = round_datatype(tuple(dims), k)

@@ -226,8 +226,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older JAX: one dict per module
-        cost = cost[0] if cost else {}
     text = compiled.as_text()
     rep = parse_hlo(text)
     # Loop-aware accounting: while (scan) bodies weighted by trip count —

@@ -26,13 +26,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, make_serve_step
 from repro.parallel.sharding import ShardingRules
 from repro.runtime.serving import ContinuousBatcher, DisaggregatedServer, \
     Request
 
 
-def _batcher_step(serve, memory=None):
+def batcher_step(serve, memory=None):
     """Adapt ``make_serve_step``'s ``(params, caches, toks[, memory]) ->
     (nxt, logits, caches)`` to the batcher's ``(params, toks, caches) ->
     (logits, caches)`` contract.  A fixed ``memory`` (enc-dec frontend)
@@ -59,7 +60,7 @@ def legacy_prefill_decode(model, params, serve, prompts, gen, memory=None):
     B, L = prompts.shape
     batcher = ContinuousBatcher(
         model, params, max_batch=B, max_seq=L + gen,
-        serve_step=_batcher_step(serve, memory))
+        serve_step=batcher_step(serve, memory))
     for i in range(B):
         batcher.submit(Request(i, [int(t) for t in prompts[i]], gen))
     done = batcher.run()
@@ -83,6 +84,7 @@ def main(argv=None):
                     help="prefill ranks (default: cost-model split)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     params = jax.jit(model.init)(jax.random.PRNGKey(0))
@@ -114,7 +116,7 @@ def main(argv=None):
         server = DisaggregatedServer(
             model, params, comm, max_seq=max_seq, decode_batch=B,
             n_prefill=args.n_prefill,
-            serve_step=_batcher_step(serve))
+            serve_step=batcher_step(serve))
         for r in reqs:
             server.submit(r)
         done = server.run()
@@ -129,7 +131,7 @@ def main(argv=None):
     else:
         batcher = ContinuousBatcher(
             model, params, max_batch=B, max_seq=max_seq,
-            serve_step=_batcher_step(serve, memory))
+            serve_step=batcher_step(serve, memory))
         for r in reqs:
             batcher.submit(r)
         done = batcher.run()

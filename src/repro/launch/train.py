@@ -1,8 +1,9 @@
 """Training launcher: end-to-end driver over any registered architecture.
 
-Wires config -> model -> sharded init -> fault-tolerant Trainer.  On this
-CPU container it is exercised with ``--smoke`` (reduced config, small
-mesh); the full configs are exercised via the dry-run.
+Wires config -> model -> sharded init -> fault-tolerant Trainer.  On the
+CPU it runs with ``--smoke`` (reduced config); on the chips of a TPU host
+``--mesh host`` shards over every chip present (``(pod=2, data=2)`` on
+four).
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b --smoke \
       --steps 50 --batch 8 --seq 64
@@ -16,8 +17,10 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
 from repro.data import CopyTaskConfig, DataConfig, SyntheticLM
 from repro.models import build_model, make_train_step
 from repro.models.common import init_params, param_shardings
@@ -34,12 +37,21 @@ def build_training(cfg, mesh, rules, *, lr=3e-4, warmup=100, total=10000,
     if mesh is not None:
         shardings = param_shardings(model.specs(), mesh, rules)
         init_fn = jax.jit(model.init, out_shardings=shardings)
+        # the moments are made from shapes alone, so their placement is
+        # stated: sharded like the params, not all on the first device
+        opt_init = jax.jit(opt.init, out_shardings={
+            "mu": shardings, "nu": shardings,
+            "step": NamedSharding(mesh, P())})
     else:
         init_fn = jax.jit(model.init)
+        opt_init = jax.jit(opt.init)
     params = init_fn(jax.random.PRNGKey(seed))
-    opt_state = jax.jit(opt.init)(params)
+    opt_state = opt_init(params)
+    # params and optimizer state are donated: the step's outputs replace
+    # them, so their buffers are reused instead of held twice
     step_fn = jax.jit(make_train_step(model, opt, mesh, rules,
-                                      grad_accum=grad_accum))
+                                      grad_accum=grad_accum),
+                      donate_argnums=(0, 1))
     return model, opt, params, opt_state, step_fn
 
 
@@ -53,17 +65,24 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--task", choices=("lm", "copy"), default="copy")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CHECKOUT / "runs" / "ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--mesh", choices=("none", "debug", "debug_multi"),
-                    default="none")
+    ap.add_argument("--mesh", choices=("none", "host", "debug",
+                                       "debug_multi"),
+                    default="none",
+                    help="host: (pod, data) over every chip present; "
+                    "debug/debug_multi: the CPU test meshes")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = None
     rules = ShardingRules()
-    if args.mesh != "none":
+    if args.mesh == "host":
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh()
+    elif args.mesh != "none":
         from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh(multi_pod=(args.mesh == "debug_multi"))
 

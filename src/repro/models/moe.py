@@ -36,7 +36,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.autotune import lookup_ragged_measured
 from repro.core.comm import torus_comm
 from repro.core.ragged import next_pow2
-from repro.core.tuning import choose_ragged_algorithm, default_links
+from repro.core.tuning import choose_ragged_algorithm, default_links, \
+    mesh_links
 from repro.kernels import ops as kops
 from repro.models.common import ParamSpec, silu, gelu
 from repro.parallel.sharding import ShardingRules, constrain, ep_axes, \
@@ -102,6 +103,13 @@ def moe_ep_comm(cfg: ModelConfig, mesh, axes):
     return torus_comm(mesh, axes, variant=cfg.a2a_variant)
 
 
+def _ep_links(cfg: ModelConfig, mesh, axes):
+    """Per-axis links observed from the mesh's devices for the EP plans.
+    Under ``"autotune"`` none are given, so that a tuning-DB record's
+    measured links take precedence."""
+    return None if cfg.a2a_backend == "autotune" else mesh_links(mesh, axes)
+
+
 def moe_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int):
     """The one A2APlan shared by dispatch and combine for this MoE layer.
 
@@ -121,7 +129,7 @@ def moe_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int):
     return comm.all_to_all(
         block_shape=(E_loc, C, cfg.d_model), dtype=cfg.cdtype,
         backend=cfg.a2a_backend, n_chunks=cfg.a2a_chunks,
-        max_chunks=cfg.a2a_chunks or 4)
+        max_chunks=cfg.a2a_chunks or 4, links=_ep_links(cfg, mesh, axes))
 
 
 def moe_ragged_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int,
@@ -146,7 +154,8 @@ def moe_ragged_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int,
     return comm.ragged_all_to_all(
         row_shape=(cfg.d_model,), dtype=cfg.cdtype,
         max_count=window, avg_count=avg, backend=cfg.a2a_backend,
-        n_chunks=cfg.a2a_chunks, max_chunks=cfg.a2a_chunks or 4)
+        n_chunks=cfg.a2a_chunks, max_chunks=cfg.a2a_chunks or 4,
+        links=_ep_links(cfg, mesh, axes))
 
 
 def moe_dropless_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int,
@@ -181,8 +190,9 @@ def moe_dropless_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int,
             backend = rec["winner"]["backend"]
     if backend is None:
         row_bytes = cfg.d_model * jnp.dtype(cfg.cdtype).itemsize
+        links = mesh_links(mesh, axes) or default_links(comm.axis_names)
         sched = choose_ragged_algorithm(
-            comm.dims, default_links(comm.axis_names), row_bytes,
+            comm.dims, links, row_bytes,
             next_pow2(window), max_chunks=cfg.a2a_chunks or 4,
             density=density)
         backend = sched.kind
@@ -190,7 +200,8 @@ def moe_dropless_a2a_plan(cfg: ModelConfig, mesh, axes, E_loc: int, C: int,
         avg = min(float(window), max(1.0, cfg.top_k * n_loc / comm.p))
         return comm.sparse_all_to_all(
             row_shape=(cfg.d_model,), dtype=cfg.cdtype, max_count=window,
-            avg_count=avg, density=density)
+            avg_count=avg, density=density,
+            links=_ep_links(cfg, mesh, axes))
     return moe_ragged_a2a_plan(cfg, mesh, axes, E_loc, C, n_loc)
 
 

@@ -13,8 +13,8 @@ from repro.core.guidelines import Measurement, check_guidelines
 from repro.core.hlo_inspect import parse_hlo, shape_bytes
 from repro.core.tuning import (DCN, ICI, choose_algorithm,
                                candidate_factorizations,
-                               crossover_block_bytes, predict_direct,
-                               predict_factorized)
+                               crossover_block_bytes, mesh_links,
+                               predict_direct, predict_factorized)
 
 
 class TestDimsCreate:
@@ -63,6 +63,29 @@ class TestTuning:
         t_f = predict_factorized((16, 2), (ICI, DCN), 1024, 32)
         t_d = predict_direct(32, 1024, DCN)
         assert t_f < t_d
+
+    @pytest.mark.parametrize("slices,want", [
+        ([[0, 0], [0, 0]], (ICI, ICI)),
+        ([[0, 0], [1, 1]], (ICI, DCN)),      # pods in different slices
+        (None, (ICI, ICI)),                  # one slice: no slice_index
+    ])
+    def test_mesh_links_observe_slices(self, slices, want):
+        import types
+        import numpy as np
+        devs = np.empty((2, 2), dtype=object)
+        for i in range(2):
+            for j in range(2):
+                devs[i, j] = types.SimpleNamespace(platform="tpu")
+                if slices is not None:
+                    devs[i, j].slice_index = slices[i][j]
+        mesh = types.SimpleNamespace(devices=devs,
+                                     axis_names=("pod", "data"))
+        assert mesh_links(mesh, ("data", "pod")) == want
+
+    def test_mesh_links_defer_to_names_off_tpu(self):
+        import jax
+        mesh = jax.make_mesh((1, 1), ("pod", "data"))
+        assert mesh_links(mesh, ("data", "pod")) is None
 
     def test_candidates_cover_paper_sweep(self):
         cands = candidate_factorizations(1152)
