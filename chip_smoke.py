@@ -113,10 +113,11 @@ GMM_TOL = 2.0 ** -7
 # differently around the two exchanges).  The exchange moves bytes
 # unchanged, so the first loss, the same forward on the same params and
 # tokens, agrees to float32 reduction order: 1e-5 relative (8.1e-7
-# measured on the chip); tokens reaching a wrong expert change it far more.
-# Later losses follow AdamW updates whose normalized steps turn
-# rounding-level gradient differences into parameter differences of up to
-# the learning rate per entry, so the runs drift: 1e-2 (3.3e-3 measured).
+# measured on the TPU v5e 2x2 host); tokens reaching a wrong expert change
+# it far more.  Later losses follow AdamW updates whose normalized steps
+# turn rounding-level gradient differences into parameter differences of up
+# to the learning rate per entry, so the runs drift: 1e-2 (3.3e-3 measured
+# there).
 MOE_FIRST_LOSS_RTOL = 1e-5
 MOE_LOSS_RTOL = 1e-2
 ATTN_TOL = 1e-2

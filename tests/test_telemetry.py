@@ -327,23 +327,31 @@ class TestDisabledOverhead:
         jax.block_until_ready(raw(x))
         assert not get_tracer().enabled
 
-        def timed(fn, n=400):
+        def timed(fn, n=50):
             t0 = time.perf_counter()
             for _ in range(n):
                 fn(x)
             jax.block_until_ready(fn(x))
             return time.perf_counter() - t0
 
-        # Interleave the raw/wrapped rounds and take each side's best:
-        # a load spike (e.g. the rest of the suite running) hits both
-        # paths alike instead of skewing whichever block it lands in.
-        t_raw = t_wrapped = float("inf")
-        for _ in range(7):
-            t_raw = min(t_raw, timed(raw))
-            t_wrapped = min(t_wrapped, timed(wrapped))
-        overhead = t_wrapped / t_raw - 1.0
+        # Time many short raw/wrapped rounds back to back, alternating
+        # which goes first, and take the median of the paired ratios: a
+        # load spike or clock drift (e.g. the rest of the suite running)
+        # hits both rounds of a pair alike.  Best-of-a-few long rounds
+        # let slow drift land on one side and swung the ratio by +-9%
+        # with the two sides identical; pairs hold that to +-0.3%.
+        ratios = []
+        for i in range(300):
+            if i % 2:
+                t_wrapped = timed(wrapped)
+                t_raw = timed(raw)
+            else:
+                t_raw = timed(raw)
+                t_wrapped = timed(wrapped)
+            ratios.append(t_wrapped / t_raw)
+        overhead = sorted(ratios)[len(ratios) // 2] - 1.0
         assert overhead < 0.05, \
-            f"disabled-tracer overhead {overhead:.1%} >= 5% " \
-            f"(raw {t_raw:.4f}s, wrapped {t_wrapped:.4f}s)"
+            f"disabled-tracer overhead {overhead:.1%} >= 5% (median of " \
+            f"{len(ratios)} paired rounds of 50 calls)"
         # and the loop really stayed on the fused path: nothing recorded
         assert get_tracer().spans() == []
