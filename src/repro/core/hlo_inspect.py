@@ -208,6 +208,31 @@ def parse_hlo(text: str) -> HloReport:
     return report
 
 
+_METADATA_RE = re.compile(r",?\s*metadata=\{[^}]*\}")
+_NAME_RE = re.compile(r"%[\w.\-]+")
+
+
+def program_structure(text: str) -> str:
+    """Compiled HLO text reduced to the program itself: without the debug
+    tables (``FileNames`` .. ``StackFrames``), without any
+    ``metadata={...}``, and with every ``%name`` renumbered in order of
+    first use.  Instruction names follow the lowered ops' locations, so
+    two programs that differ only in their ``jax.named_scope``s compare
+    equal here."""
+    lines, debug = [], False
+    for line in text.splitlines():
+        if line == "FileNames":
+            debug = True
+        elif debug and (line.startswith("%") or line.startswith("ENTRY")):
+            debug = False
+        if not debug:
+            lines.append(_METADATA_RE.sub("", line))
+    names: dict[str, str] = {}
+    return _NAME_RE.sub(
+        lambda m: names.setdefault(m.group(0), f"%i{len(names)}"),
+        "\n".join(lines))
+
+
 def collective_bytes_of(lowered_or_text) -> float:
     text = lowered_or_text if isinstance(lowered_or_text, str) \
         else lowered_or_text.as_text()

@@ -56,6 +56,7 @@ import time
 import warnings
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -92,6 +93,13 @@ from .tuning import (
 
 BACKENDS = ("tuned", "autotune", "direct", "factorized", "pipelined",
             "overlap")
+
+
+def _scope(kind: str):
+    """The ``a2a[<kind>]`` name scope over one exchange: it names every op
+    of the exchange, collectives and local packing alike, in the compiled
+    HLO's ``op_name`` metadata.  Metadata only — the program is the same."""
+    return jax.named_scope(f"a2a[{kind}]")
 
 
 class A2APlan:
@@ -175,13 +183,16 @@ class A2APlan:
         return self._run(x, self.rev_order)
 
     def _run(self, x, order):
-        if self.backend == "direct":
-            return _direct_impl(x, self.axis_names)
-        if self.backend == "factorized":
-            return _factorized_impl(x, self.axis_names, variant=self.variant,
-                                    round_order=order)
-        return _overlapped_impl(x, self.axis_names, n_chunks=self.n_chunks,
-                                variant=self.variant, round_order=order)
+        with _scope(self.backend):
+            if self.backend == "direct":
+                return _direct_impl(x, self.axis_names)
+            if self.backend == "factorized":
+                return _factorized_impl(x, self.axis_names,
+                                        variant=self.variant,
+                                        round_order=order)
+            return _overlapped_impl(x, self.axis_names,
+                                    n_chunks=self.n_chunks,
+                                    variant=self.variant, round_order=order)
 
     def tiled(self, x, split_axis: int, concat_axis: int, *,
               reverse: bool = False):
@@ -189,17 +200,19 @@ class A2APlan:
         reversed(axis_names), split_axis, concat_axis, tiled=True)``; the
         MoE-dispatch and Ulysses re-shard form."""
         order = self.rev_order if reverse else self.order
-        if self.backend == "direct":
-            return _direct_tiled_impl(x, self.axis_names, split_axis,
-                                      concat_axis)
-        if self.backend == "factorized":
-            return _factorized_tiled_impl(x, self.axis_names, split_axis,
-                                          concat_axis, variant=self.variant,
+        with _scope(self.backend):
+            if self.backend == "direct":
+                return _direct_tiled_impl(x, self.axis_names, split_axis,
+                                          concat_axis)
+            if self.backend == "factorized":
+                return _factorized_tiled_impl(
+                    x, self.axis_names, split_axis, concat_axis,
+                    variant=self.variant, round_order=order)
+            return _overlapped_tiled_impl(x, self.axis_names, split_axis,
+                                          concat_axis,
+                                          n_chunks=self.n_chunks,
+                                          variant=self.variant,
                                           round_order=order)
-        return _overlapped_tiled_impl(x, self.axis_names, split_axis,
-                                      concat_axis, n_chunks=self.n_chunks,
-                                      variant=self.variant,
-                                      round_order=order)
 
     def overlap(self, x, compute_fn: Callable | None = None, *,
                 reverse: bool = True, chunk_axis: int | None = None):
@@ -232,7 +245,6 @@ class A2APlan:
         if mesh is None:
             raise ValueError("plan was built without a Mesh; pass one")
         if mesh not in self._host_fns:
-            import jax
             spec = P(tuple(reversed(self.axis_names)))
 
             def local(x):   # x: (1, p, *block) per device
@@ -277,7 +289,6 @@ class A2APlan:
         """Per-round jitted host fns in forward round order — the
         stepped traced path (factorized backend only)."""
         if mesh not in self._round_fns:
-            import jax
             spec = P(tuple(reversed(self.axis_names)))
             names, sizes = _skip_trivial(self.axis_names, self.dims)
             fns = []
@@ -294,7 +305,6 @@ class A2APlan:
         return self._round_fns[mesh]
 
     def _traced_execute(self, tr, mesh, fast, x):
-        import jax
         det = telemetry.drift_detector()
         key = self._drift_key()
         preds = self._per_axis_predictions()
@@ -777,7 +787,8 @@ class TransposePlan:
         if x.shape != self.in_shape:
             raise ValueError(f"pencil shape {x.shape} != plan in_shape "
                              f"{self.in_shape}")
-        return self.inner.tiled(x, self.split_axis, self.concat_axis)
+        with _scope(self.kind):
+            return self.inner.tiled(x, self.split_axis, self.concat_axis)
 
     def inverse_apply(self, y):
         """The exact inverse re-shard (the tiled collective with split and
@@ -786,8 +797,9 @@ class TransposePlan:
         if y.shape != self.out_shape:
             raise ValueError(f"pencil shape {y.shape} != plan out_shape "
                              f"{self.out_shape}")
-        return self.inner.tiled(y, self.concat_axis, self.split_axis,
-                                reverse=True)
+        with _scope(self.kind):
+            return self.inner.tiled(y, self.concat_axis, self.split_axis,
+                                    reverse=True)
 
     # -- host-level convenience -------------------------------------------
 
@@ -824,7 +836,6 @@ class TransposePlan:
         out_spec = d_out if out_spec is None else out_spec
         fkey = (mesh, in_spec, out_spec)
         if fkey not in self._host_fns:
-            import jax
             self._host_fns[fkey] = jax.jit(jax.shard_map(
                 self.apply, mesh=mesh, in_specs=in_spec,
                 out_specs=out_spec))
@@ -855,7 +866,6 @@ class TransposePlan:
         mesh axes (the default-spec harness form)."""
         fkey = (mesh, in_spec, out_spec)
         if fkey not in self._step_fns:
-            import jax
             import jax.numpy as _jnp
             p, s, c = self.p, self.split_axis, self.concat_axis
             block_spec = P(tuple(reversed(self.axis_names)))
@@ -880,7 +890,6 @@ class TransposePlan:
         return self._step_fns[fkey]
 
     def _traced_execute(self, tr, mesh, fast, x, in_spec, out_spec):
-        import jax
         det = telemetry.drift_detector()
         key = self._drift_key()
         preds = self.inner._per_axis_predictions()
@@ -1118,18 +1127,20 @@ class RaggedA2APlan:
         returns ``(recv, recv_counts)`` — ``recv[i]`` the ``(bucket,
         *row)`` window received from rank ``i``."""
         from .ragged import _bucketed_impl
-        return _bucketed_impl(x, send_counts, data_plan=self.data,
-                              counts_plan=self.counts_plan,
-                              axis_names=self.axis_names)
+        with _scope("ragged"):
+            return _bucketed_impl(x, send_counts, data_plan=self.data,
+                                  counts_plan=self.counts_plan,
+                                  axis_names=self.axis_names)
 
     def reverse(self, x, send_counts):
         """The combine-direction bucketed exchange (drain round order);
         ``send_counts`` is typically the ``recv_counts`` of the matching
         ``forward``."""
         from .ragged import _bucketed_impl
-        return _bucketed_impl(x, send_counts, data_plan=self.data,
-                              counts_plan=self.counts_plan,
-                              axis_names=self.axis_names, reverse=True)
+        with _scope("ragged"):
+            return _bucketed_impl(x, send_counts, data_plan=self.data,
+                                  counts_plan=self.counts_plan,
+                                  axis_names=self.axis_names, reverse=True)
 
     def occupancy(self, send_counts):
         """Measured occupancy of one call (traced scalar): useful rows
@@ -1165,7 +1176,6 @@ class RaggedA2APlan:
         if mesh is None:
             raise ValueError("plan was built without a Mesh; pass one")
         if mesh not in self._host_fns:
-            import jax
             axes = tuple(reversed(self.axis_names))
             x_spec = P(axes)
             c_spec = P(axes)
@@ -1199,7 +1209,6 @@ class RaggedA2APlan:
         """Jitted counts phase alone: global ``(p, p)`` send counts ->
         global ``(p, p)`` per-rank recv counts."""
         if mesh not in self._counts_fns:
-            import jax
             from .ragged import (_counts_matrix_impl,
                                  _recv_counts_from_matrix)
             spec = P(tuple(reversed(self.axis_names)))
@@ -1214,7 +1223,6 @@ class RaggedA2APlan:
         return self._counts_fns[mesh]
 
     def _traced_execute(self, tr, mesh, x, c):
-        import jax
         det = telemetry.drift_detector()
         key = self._drift_key()
         with tr.span("plan.execute", cat="plan", kind="ragged",
@@ -1519,13 +1527,15 @@ class SparseA2APlan:
         convention as :meth:`RaggedA2APlan.forward`, with empty per-peer
         lanes skipped; rows beyond ``recv_counts[i]`` are unspecified."""
         from .sparse import _sparse_bucketed_impl
-        return _sparse_bucketed_impl(x, send_counts, plan=self)
+        with _scope("sparse"):
+            return _sparse_bucketed_impl(x, send_counts, plan=self)
 
     def reverse(self, x, send_counts):
         """The combine-direction sparse exchange (drain round order)."""
         from .sparse import _sparse_bucketed_impl
-        return _sparse_bucketed_impl(x, send_counts, plan=self,
-                                     reverse=True)
+        with _scope("sparse"):
+            return _sparse_bucketed_impl(x, send_counts, plan=self,
+                                         reverse=True)
 
     def occupancy(self, send_counts):
         """Measured occupancy of one call (traced scalar): useful rows
@@ -1572,7 +1582,6 @@ class SparseA2APlan:
         if mesh is None:
             raise ValueError("plan was built without a Mesh; pass one")
         if mesh not in self._host_fns:
-            import jax
             axes = tuple(reversed(self.axis_names))
             x_spec = P(axes)
             c_spec = P(axes)
@@ -1608,7 +1617,6 @@ class SparseA2APlan:
         level (the skip predicates live inside the trace), so per-round
         device attribution comes from the ``named_scope`` annotations in
         the profile, not host spans."""
-        import jax
         det = telemetry.drift_detector()
         key = self._drift_key()
         with tr.span("plan.execute", cat="plan", kind="sparse",

@@ -7,21 +7,31 @@ datatype paths routinely underperforming their analytic model on real
 hardware, so measurement can't be a one-shot autotune.  This module is
 the single observability surface for the whole stack:
 
-* :class:`Tracer` — span-based tracing on the monotonic clock with
-  nested-span attribution, a bounded ring buffer, and thread safety.
-  **Disabled by default**: ``tracer.span(...)`` returns a shared no-op
-  context manager when off, so instrumented hot paths pay one attribute
-  check.  Enabled, plan execution switches to a *stepped* per-round host
-  path (bit-exact — the rounds commute) so every dimension-wise round
-  gets a genuinely measured span.
+* :class:`Tracer` — span-based tracing with nested-span attribution, a
+  bounded ring buffer, and thread safety.  Every span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so program spans
+  reach a profiler trace's host plane, on the device trace's clock,
+  whenever a profiler session runs — whether or not the tracer is on.
+  **Disabled by default**: ``tracer.span(...)`` is then the annotation
+  alone (no ring-buffer record; with no profiler session running it
+  costs one native check), and instrumented hot paths that test
+  ``tracer.enabled`` first pay one attribute check.  Enabled, spans are
+  also recorded on the ``time.perf_counter`` clock, and plan execution
+  switches to a *stepped* per-round host path (bit-exact — the rounds
+  commute) so every dimension-wise round gets a genuinely measured span.
 * :class:`MetricsRegistry` — namespaced counters / gauges / histograms,
   plus registered *stat providers* that fold the pre-existing scattered
   dicts (``cache_stats`` / ``plan_cache_stats`` / ``autotune_stats`` /
   comm registry) into one flat snapshot, ``metrics_snapshot()`` — what
   ``TorusComm.unified_stats()`` surfaces under ``"telemetry"``.
 * :func:`Tracer.export_chrome_trace` — Chrome ``trace_event`` (Perfetto)
-  JSON so host spans line up with ``jax.profiler`` device timelines (the
-  jitted round bodies carry matching ``jax.named_scope`` annotations).
+  JSON of the recorded spans: host-only, on the tracer's own epoch, so it
+  does not line up with a device profile.  Device time per scope is read
+  from a profiler trace instead: jitted code carries ``jax.named_scope``
+  annotations (model layers, ``a2a[<backend>]``, ``a2a_round[<axis>]``)
+  that reach the compiled HLO's ``op_name`` metadata, and a trace's op
+  events, which do not carry the scope, are joined to it by instruction
+  name.
 * :class:`DriftDetector` — measured-vs-model ratios per plan and per
   torus axis, fed by the traced execution path; ``drift_ratio`` above
   ``threshold`` produces a re-tune recommendation that
@@ -29,15 +39,17 @@ the single observability surface for the whole stack:
   (``Action(kind="retune")``) and ``runtime.serving`` admission reads to
   shed load while the tuning record is stale.
 
-Stdlib only — importable from every layer without cycles; the rest of
-the stack registers providers / emits spans into the module singletons
-(:func:`get_tracer`, :func:`metrics`, :func:`drift_detector`).
+Stdlib only — importable from every layer without cycles, and without
+jax (the profiler annotation is looked up only once jax is imported);
+the rest of the stack registers providers / emits spans into the module
+singletons (:func:`get_tracer`, :func:`metrics`, :func:`drift_detector`).
 """
 
 from __future__ import annotations
 
 import json
 import math
+import sys
 import threading
 import time
 import warnings
@@ -83,9 +95,9 @@ class Span:
 
 
 class _NullSpan:
-    """The disabled-tracer span: a shared, stateless no-op context
-    manager — entering, exiting, and ``set()`` all cost one method
-    dispatch and allocate nothing."""
+    """The disabled-tracer span outside a profiler session: a shared,
+    stateless no-op context manager — entering, exiting, and ``set()``
+    all cost one method dispatch and allocate nothing."""
 
     __slots__ = ()
 
@@ -100,6 +112,29 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` while a profiler
+    session runs, else ``None``.  jax is looked up lazily: until it is
+    imported no session can run, and this module stays jax-free."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        class _Annotation(TraceAnnotation):
+            """The disabled tracer's span: the annotation alone."""
+
+            def set(self, **attrs):
+                return self
+
+        _ANNOTATION = _Annotation
+    if not _ANNOTATION.is_enabled():
+        return None
+    return _ANNOTATION(name)
 
 
 class _ActiveSpan:
@@ -108,7 +143,7 @@ class _ActiveSpan:
     the exception type so the trace shows *where* a run died."""
 
     __slots__ = ("_tracer", "name", "attrs", "start", "span_id",
-                 "parent_id", "thread_id")
+                 "parent_id", "thread_id", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -122,6 +157,9 @@ class _ActiveSpan:
         self.span_id = tr._next_id()
         self.thread_id = threading.get_ident()
         stack.append(self.span_id)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.start = time.perf_counter()
         return self
 
@@ -133,6 +171,8 @@ class _ActiveSpan:
 
     def __exit__(self, exc_type, exc, tb):
         end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -147,10 +187,11 @@ class _ActiveSpan:
 class Tracer:
     """Span recorder over a bounded ring buffer.
 
-    ``enabled`` gates everything: when ``False`` (the default),
-    :meth:`span` returns the shared :data:`_NULL_SPAN` and no state is
-    touched — the documented overhead contract is <5% on a tight
-    plan-execute loop (``tests/test_telemetry.py`` enforces it).  The
+    ``enabled`` gates the recording: when ``False`` (the default),
+    :meth:`span` returns the profiler annotation alone (the shared
+    :data:`_NULL_SPAN` when no profiler session runs) and no tracer
+    state is touched — the documented overhead contract is <5% on a
+    tight plan-execute loop (``tests/test_telemetry.py`` enforces it).  The
     ring buffer (``capacity`` completed spans) makes a week-long run
     safe to trace: overflow evicts the oldest span and bumps
     ``dropped`` instead of growing without bound.
@@ -192,9 +233,11 @@ class Tracer:
         return self._buf.maxlen or 0
 
     def span(self, name: str, **attrs):
-        """Open a span context manager; a no-op when disabled."""
+        """Open a span context manager: a profiler annotation named
+        ``name`` (while a profiler session runs), also recorded into the
+        ring buffer when the tracer is enabled."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _annotation(name) or _NULL_SPAN
         return _ActiveSpan(self, name, attrs)
 
     def spans(self) -> list[Span]:
@@ -218,7 +261,9 @@ class Tracer:
         """The spans as a Chrome ``trace_event`` document (Perfetto /
         ``chrome://tracing`` loadable).  Complete spans map to ``"X"``
         (duration) events; timestamps are microseconds since the
-        tracer's epoch so the timeline starts near zero.  Writes JSON to
+        tracer's epoch so the timeline starts near zero — host only, on
+        the tracer's own clock (a profiler trace holds the same spans on
+        the device's clock, see :meth:`span`).  Writes JSON to
         ``path`` when given; always returns the document."""
         events = []
         for s in self.spans():
@@ -448,7 +493,6 @@ class DriftDetector:
             dq.append(ratio)
             self._last[key] = (float(predicted_seconds),
                                float(measured_seconds))
-        metrics().counter("drift.observations").inc()
         return self.drift_ratio(key)
 
     def drift_ratio(self, key: str) -> float | None:

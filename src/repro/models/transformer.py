@@ -59,9 +59,10 @@ def _norm_specs(cfg):
 
 
 def _apply_norm(p, x, cfg):
-    if cfg.norm == "layernorm":
-        return layer_norm(x, p["g"], p["b"])
-    return rms_norm(x, p["g"])
+    with jax.named_scope("norm"):
+        if cfg.norm == "layernorm":
+            return layer_norm(x, p["g"], p["b"])
+        return rms_norm(x, p["g"])
 
 
 def _mixer_specs(cfg, kind):
@@ -172,41 +173,45 @@ def cache_logical_axes(cfg: ModelConfig):
 
 def _apply_position(pp, x, cfg, mixer, ffn, mesh, rules, positions,
                     state=None, decode=False):
-    """One (mixer, ffn) position.  Returns (x, aux, new_state)."""
+    """One (mixer, ffn) position.  Returns (x, aux, new_state).  Each
+    part runs under a ``jax.named_scope`` (``norm``, ``mixer.<kind>``,
+    ``ffn`` or ``moe``) that names its ops in the compiled HLO."""
     h = _apply_norm(pp["norm1"], x, cfg)
     new_state = state
-    if mixer == "attn":
-        if decode:
-            y, new_state = attn.decode_attention(pp["mixer"], h, state,
-                                                 positions, cfg)
+    with jax.named_scope(f"mixer.{mixer}"):
+        if mixer == "attn":
+            if decode:
+                y, new_state = attn.decode_attention(pp["mixer"], h, state,
+                                                     positions, cfg)
+            else:
+                y = attn.attention_block(
+                    pp["mixer"], h, cfg, causal=True, positions=positions,
+                    mesh=mesh, rules=rules)
+        elif mixer == "mamba":
+            y, new_state = mamba_mod.mamba_block(pp["mixer"], h, cfg,
+                                                 state=state)
+        elif mixer == "spectral":
+            y, new_state = spectral_mod.spectral_block(pp["mixer"], h, cfg,
+                                                       state=state)
+        elif mixer == "mlstm":
+            y, new_state = xlstm_mod.mlstm_block(pp["mixer"], h, cfg,
+                                                 state=state)
+        elif mixer == "slstm":
+            y, new_state = xlstm_mod.slstm_block(pp["mixer"], h, cfg,
+                                                 state=state)
         else:
-            y = attn.attention_block(
-                pp["mixer"], h, cfg, causal=True, positions=positions,
-                mesh=mesh, rules=rules)
-    elif mixer == "mamba":
-        y, new_state = mamba_mod.mamba_block(pp["mixer"], h, cfg,
-                                             state=state)
-    elif mixer == "spectral":
-        y, new_state = spectral_mod.spectral_block(pp["mixer"], h, cfg,
-                                                   state=state)
-    elif mixer == "mlstm":
-        y, new_state = xlstm_mod.mlstm_block(pp["mixer"], h, cfg,
-                                             state=state)
-    elif mixer == "slstm":
-        y, new_state = xlstm_mod.slstm_block(pp["mixer"], h, cfg,
-                                             state=state)
-    else:
-        raise ValueError(mixer)
+            raise ValueError(mixer)
     x = x + y.astype(x.dtype)
 
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
         h = _apply_norm(pp["norm2"], x, cfg)
-        if ffn == "moe":
-            y, aux = moe_mod.moe_block(pp["ffn"], h, cfg, mesh=mesh,
-                                       rules=rules)
-        else:
-            y = ffn_mod.ffn_block(pp["ffn"], h, cfg)
+        with jax.named_scope("moe" if ffn == "moe" else "ffn"):
+            if ffn == "moe":
+                y, aux = moe_mod.moe_block(pp["ffn"], h, cfg, mesh=mesh,
+                                           rules=rules)
+            else:
+                y = ffn_mod.ffn_block(pp["ffn"], h, cfg)
         x = x + y.astype(x.dtype)
     x = constrain(x, ACT_SPEC, mesh, rules)
     return x, aux, new_state
@@ -259,15 +264,16 @@ class Model:
 
     # ---- embedding / head ----
     def embed(self, params, tokens):
-        e = jnp.take(params["embed"], tokens, axis=0)
-        return e.astype(self.cfg.cdtype)
+        with jax.named_scope("embed"):
+            e = jnp.take(params["embed"], tokens, axis=0)
+            return e.astype(self.cfg.cdtype)
 
     def logits(self, params, x):
         w = params.get("lm_head", params["embed"])
-        out = jnp.einsum("bsd,vd->bsv", x.astype(self.cfg.cdtype),
-                         w.astype(self.cfg.cdtype),
-                         preferred_element_type=jnp.float32)
-        return out  # f32
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bsd,vd->bsv", x.astype(self.cfg.cdtype),
+                              w.astype(self.cfg.cdtype),
+                              preferred_element_type=jnp.float32)  # f32
 
     # ---- full-sequence forward (train / prefill) ----
     def forward(self, params, tokens, *, mesh=None, rules=None,

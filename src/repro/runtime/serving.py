@@ -39,6 +39,7 @@ mid-stream rebuild).
 from __future__ import annotations
 
 import math
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -60,6 +61,10 @@ class Request:
     # how many generated tokens are already folded into ``prompt`` by
     # ``requeue_inflight`` — keeps a second requeue from re-folding them
     folded: int = 0
+    # ``time.perf_counter`` stamps: submitted to the server, and given a
+    # decode slot (the last admission, after a requeue)
+    submitted_s: float | None = None
+    admitted_s: float | None = None
 
 
 def _finished(req: Request) -> bool:
@@ -210,6 +215,7 @@ class ContinuousBatcher:
 
     # ---- scheduling ----
     def submit(self, req: Request):
+        req.submitted_s = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -238,6 +244,7 @@ class ContinuousBatcher:
                        "pos": self.caches["pos"].at[b].set(int(pos))}
         self.slots[b] = req
         self.prefill_cursor[b] = len(req.prompt)
+        req.admitted_s = time.perf_counter()
         return True
 
     # ---- elasticity ----
@@ -292,6 +299,7 @@ class ContinuousBatcher:
                 self.caches = _reset_slot(self.caches, self._fresh, b)
                 self.slots[b] = req
                 self.prefill_cursor[b] = 0
+                req.admitted_s = time.perf_counter()
 
     def _next_tokens(self) -> np.ndarray:
         toks = np.zeros((self.max_batch, 1), np.int32)
@@ -307,29 +315,38 @@ class ContinuousBatcher:
 
     # ---- main loop ----
     def step(self):
-        self._admit()
-        if all(s is None for s in self.slots):
-            return False
-        toks = jnp.asarray(self._next_tokens())
-        logits, self.caches = self._step(self.params, toks, self.caches)
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
-        for b, req in enumerate(self.slots):
-            if req is None:
-                continue
-            c = self.prefill_cursor[b]
-            if c < len(req.prompt) - 1:
-                self.prefill_cursor[b] = c + 1         # still prefilling
-                continue
-            if c == len(req.prompt) - 1:
-                self.prefill_cursor[b] = c + 1         # first generation
-            req.generated.append(int(nxt[b]))
-            if _finished(req):
-                self.done[req.rid] = list(req.generated)
-                self.slots[b] = None                   # free -> re-admit
-                telemetry.metrics().counter(
-                    "serving.requests_completed").inc()
-        self.ticks += 1
-        telemetry.metrics().counter("serving.decode_ticks").inc()
+        """One tick: admit, feed one token per occupied slot, dispatch
+        the step, pull the next tokens, book them.  Each phase is a
+        ``serve.step.*`` span under ``serve.step``."""
+        tr = telemetry.get_tracer()
+        with tr.span("serve.step", cat="serving", tick=self.ticks):
+            with tr.span("serve.step.admit", cat="serving"):
+                self._admit()
+            if all(s is None for s in self.slots):
+                return False
+            with tr.span("serve.step.feed", cat="serving"):
+                toks = jnp.asarray(self._next_tokens())
+            with tr.span("serve.step.dispatch", cat="serving"):
+                logits, self.caches = self._step(self.params, toks,
+                                                 self.caches)
+            with tr.span("serve.step.pull", cat="serving"):
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                 np.int32)
+            with tr.span("serve.step.bookkeep", cat="serving"):
+                for b, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    c = self.prefill_cursor[b]
+                    if c < len(req.prompt) - 1:
+                        self.prefill_cursor[b] = c + 1     # still prefilling
+                        continue
+                    if c == len(req.prompt) - 1:
+                        self.prefill_cursor[b] = c + 1     # first generation
+                    req.generated.append(int(nxt[b]))
+                    if _finished(req):
+                        self.done[req.rid] = list(req.generated)
+                        self.slots[b] = None               # free -> re-admit
+            self.ticks += 1
         return True
 
     def run(self, max_ticks: int = 100_000):
@@ -681,6 +698,7 @@ class DisaggregatedServer:
 
     # ---- scheduling ----
     def submit(self, req: Request):
+        req.submitted_s = time.perf_counter()
         self.admission.submit(req)
 
     @property

@@ -296,3 +296,35 @@ class TestSpectral:
         D, Ein = cfg.d_model, cfg.ssm_expand * cfg.d_model
         per_layer = D * 2 * Ein + Ein * (3 * cfg.ssm_state + 2) + Ein * D
         assert n >= cfg.n_layers * per_layer
+
+
+class TestLayerScopes:
+    """Each model layer runs under a ``jax.named_scope`` that names its
+    ops in the compiled HLO, and the scopes change nothing else."""
+
+    @staticmethod
+    def _decode_hlo(cfg):
+        m = build_model(cfg)
+        p = jax.eval_shape(m.init, KEY)
+        caches = jax.eval_shape(lambda: m.init_caches(2, 16))
+        toks = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+        step = jax.jit(lambda p, t, c: m.decode_step(p, t, c))
+        return step.lower(p, toks, caches).compile().as_text()
+
+    def test_decode_step_hlo_carries_layer_scopes(self):
+        import re
+        text = self._decode_hlo(_cfg("dense"))
+        paths = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in ("embed", "norm", "mixer.attn", "ffn", "lm_head"):
+            assert any(f"/{scope}/" in p for p in paths), scope
+
+    def test_scopes_leave_the_program_unchanged(self, monkeypatch):
+        import contextlib
+        from repro.core.hlo_inspect import program_structure
+        cfg = _cfg("dense")
+        scoped = self._decode_hlo(cfg)
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = self._decode_hlo(cfg)
+        assert "mixer.attn" in scoped and "mixer.attn" not in bare
+        assert program_structure(scoped) == program_structure(bare)

@@ -207,3 +207,13 @@ def test_telemetry_12dev(tmp_path):
     assert "OK export" in out
     assert "OK check_telemetry" in out
     assert trace.exists()
+
+
+def test_exchange_scopes_4dev():
+    # Every dense backend's exchange runs under a2a[<backend>] in the
+    # compiled HLO's op_name metadata, and the scope leaves the compiled
+    # program unchanged.
+    out = run_device_script("check_scopes.py", devices=4)
+    for backend in ("direct", "factorized", "overlap"):
+        assert f"OK a2a[{backend}]" in out
+    assert "OK check_scopes" in out

@@ -256,3 +256,33 @@ def test_batcher_stats_surface_a2a_comm_stats():
     # comm-rooted batcher scopes the stats to its comm
     assert st2["a2a_comm_stats"]["comm"]["axes"] == ["x", "y"]
     comm.free()
+
+
+def test_batcher_stamps_requests_and_spans_each_step():
+    from repro.core import telemetry
+    model, params = _model()
+    batcher = ContinuousBatcher(model, params, max_batch=2, max_seq=16)
+    reqs = [Request(i, [1 + i, 2], 2) for i in range(3)]
+    for r in reqs:
+        batcher.submit(r)
+    tr = telemetry.enable_tracing()
+    tr.clear()
+    try:
+        assert batcher.step()
+    finally:
+        telemetry.disable_tracing()
+    # two slots: the first two requests are admitted, the third waits
+    assert all(r.submitted_s <= r.admitted_s for r in reqs[:2])
+    assert reqs[2].submitted_s is not None and reqs[2].admitted_s is None
+    spans = tr.spans()
+    step = next(s for s in spans if s.name == "serve.step")
+    phases = sorted((s for s in spans if s.parent_id == step.span_id),
+                    key=lambda s: s.start)
+    assert [s.name for s in phases] == [
+        "serve.step.admit", "serve.step.feed", "serve.step.dispatch",
+        "serve.step.pull", "serve.step.bookkeep"]
+    assert step.attrs["tick"] == 0
+    tr.clear()
+    done = batcher.run()
+    assert set(done) == {0, 1, 2}
+    assert reqs[2].submitted_s <= reqs[2].admitted_s

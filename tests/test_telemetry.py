@@ -121,6 +121,63 @@ class TestTracer:
 
 
 # ---------------------------------------------------------------------------
+# Spans in the profiler's trace, on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU profiler session; the names of the host
+    plane's events."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+class TestProfilerAnnotation:
+    def test_disabled_span_reaches_the_profiler_trace(self, tmp_path):
+        tr = Tracer()
+
+        def body():
+            with tr.span("serve.step.feed", cat="serving") as sp:
+                sp.set(slots=3)
+                jnp.ones(4).block_until_ready()
+
+        names = _profiled(tmp_path, body)
+        assert names.count("serve.step.feed") == 1
+        assert tr.spans() == []           # the annotation alone
+
+    def test_enabled_span_fills_the_ring_buffer_and_the_trace(self,
+                                                              tmp_path):
+        tr = Tracer(enabled=True)
+
+        def body():
+            with tr.span("outer"):
+                with tr.span("inner", k=1):
+                    jnp.ones(4).block_until_ready()
+
+        names = _profiled(tmp_path, body)
+        assert {"outer", "inner"} <= set(names)
+        by_name = {s.name: s for s in tr.spans()}
+        assert set(by_name) == {"outer", "inner"}
+        assert by_name["inner"].parent_id == by_name["outer"].span_id
+        assert by_name["inner"].attrs == {"k": 1}
+
+    def test_no_session_no_annotation(self):
+        from repro.core.telemetry import _NULL_SPAN
+        assert Tracer().span("idle") is _NULL_SPAN
+
+
+# ---------------------------------------------------------------------------
 # Chrome-trace export: golden schema
 # ---------------------------------------------------------------------------
 
