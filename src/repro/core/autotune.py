@@ -14,11 +14,11 @@ machine x shape, ever.
 
 Search space (bounded by ``budget_seconds``):
 
-* backend per plan — ``direct`` | ``factorized`` | ``overlap``,
+* backend per plan — ``direct`` | ``factorized`` (the search times the
+  compute-free exchange, where an ``overlap`` plan runs the factorized
+  rounds unchunked, so it is no separate candidate),
 * round order — permutations of the active per-dimension rounds
   (exhaustive for d <= 3, identity + reversal beyond),
-* ``n_chunks`` for the overlap engine — powers of two up to
-  ``max_chunks`` plus the analytic ``choose_chunks`` suggestion,
 * candidate torus factorizations from ``tuning.candidate_factorizations``
   over the same devices (measured on auxiliary Cartesian meshes; recorded
   for mesh-construction decisions, never applied behind the caller's
@@ -33,8 +33,8 @@ measurements.
 Per-axis link feedback (the analytic-model bridge): a two-point
 alpha-beta fit over each active axis turns measured single-axis
 all-to-all times into per-axis :class:`~repro.core.tuning.LinkModel`
-overrides, recorded in the DB and fed back into ``choose_chunks`` /
-``predict_overlapped`` (which accept per-axis links end-to-end) — so the
+overrides, recorded in the DB and fed back into the cost model (which
+accepts per-axis links end-to-end) — so the
 cost model a DB-hit plan reports is priced with *this machine's*
 bandwidths, not the TPU-flavoured defaults.
 
@@ -70,12 +70,13 @@ from . import telemetry
 from .cache import cart_create, device_fingerprint
 from .dims import max_dims
 from .factorized import _as_tuple
-from .tuning import LinkModel, candidate_factorizations, choose_chunks
+from .tuning import LinkModel, candidate_factorizations
 
 DB_VERSION = 1
 
-# Backends the measured search may record as a winner (and that a DB
-# record is allowed to request at plan-build time).
+# Backends a DB record is allowed to request at plan-build time.  The
+# measured search records direct or factorized; overlap winners come from
+# records of earlier searches, which timed chunked compute-free rounds.
 MEASURED_BACKENDS = ("direct", "factorized", "overlap")
 
 # Backends the ragged-family measured search (``autotune_ragged``) may
@@ -607,15 +608,6 @@ def _round_orders(d_active: int, round_orders):
     return [ident, tuple(reversed(ident))]
 
 
-def _chunk_candidates(dims, links, block_bytes, max_chunks: int):
-    cands = {n for n in (2, 4, 8, 16) if n <= max_chunks}
-    model_n = choose_chunks(dims, links, block_bytes,
-                            max_chunks=max(1, max_chunks))
-    if model_n > 1:
-        cands.add(model_n)
-    return sorted(cands)
-
-
 def autotune(mesh: Mesh, axis_names, block_shape, dtype, **kwargs):
     """Measure candidate configurations, persist the winner, return its plan.
 
@@ -641,13 +633,12 @@ def autotune(mesh: Mesh, axis_names, block_shape, dtype, **kwargs):
 
 
 def _autotune_impl(mesh: Mesh, axis_names, block_shape, dtype, *,
-                   variant: str = "natural", max_chunks: int = 8,
-                   round_orders=None, include_factorizations: bool = True,
+                   variant: str = "natural", round_orders=None,
+                   include_factorizations: bool = True,
                    warmup: int = 2, repeats: int = 5,
                    budget_seconds: float = 20.0, fit_links: bool = True,
                    db: TuningDB | None = None, verbose: bool = False):
     from .plan import plan_all_to_all
-    from .tuning import default_links
 
     axes = _as_tuple(axis_names)
     dims = tuple(int(mesh.shape[a]) for a in axes)
@@ -658,7 +649,6 @@ def _autotune_impl(mesh: Mesh, axis_names, block_shape, dtype, *,
     _STATS["searches"] += 1
 
     block_shape = tuple(int(s) for s in block_shape)
-    block_bytes = math.prod(block_shape) * jnp.dtype(dtype).itemsize
     x = _operand(p, block_shape, dtype)
 
     links_fitted = None
@@ -666,38 +656,30 @@ def _autotune_impl(mesh: Mesh, axis_names, block_shape, dtype, *,
         links_fitted = _fit_axis_links(mesh, axes, dims, dtype,
                                        warmup=warmup, repeats=repeats,
                                        deadline=deadline)
-    model_links = tuple(LinkModel(l["alpha"], l["bandwidth"])
-                        for l in links_fitted) if links_fitted \
-        else default_links(axes)
 
     # ---- candidate list on the caller's axes (winner-eligible) ----
     d_active = len([D for D in dims if D > 1])
-    ident = tuple(range(d_active))
-    cands = [("direct", ident, 1)]
+    cands = [("direct", tuple(range(d_active)))]
     for order in _round_orders(d_active, round_orders):
-        cands.append(("factorized", order, 1))
-    if d_active >= 1:
-        for n in _chunk_candidates(dims, model_links, float(block_bytes),
-                                   max_chunks):
-            cands.append(("overlap", ident, n))
+        cands.append(("factorized", order))
 
     table, skipped = [], []
-    for i, (backend, order, n) in enumerate(cands):
+    for i, (backend, order) in enumerate(cands):
         if i >= 2 and time.perf_counter() > deadline:
             skipped.append({"backend": backend, "round_order": list(order),
-                            "n_chunks": n})
+                            "n_chunks": 1})
             continue
         plan = plan_all_to_all(mesh, axes, block_shape, dtype,
                                backend=backend, variant=variant,
-                               round_order=order, n_chunks=n)
+                               round_order=order)
         med = _timed(plan.host_fn(mesh), x, warmup=warmup, repeats=repeats,
-                     backend=backend, n_chunks=n,
+                     backend=backend,
                      round_order=",".join(str(o) for o in order))
         table.append({"backend": backend, "dims": list(dims),
-                      "round_order": list(order), "n_chunks": n,
+                      "round_order": list(order), "n_chunks": 1,
                       "median_us": med * 1e6, "eligible": True})
         if verbose:
-            print(f"[autotune] {backend} order={order} n={n}: "
+            print(f"[autotune] {backend} order={order}: "
                   f"{med * 1e6:.1f}us")
 
     # ---- alternative factorizations of p (informational rows: they need

@@ -4,14 +4,21 @@ The paper's algorithm is a sequence of d per-dimension collectives glued by
 double buffering; its §5 conclusion is that the win comes from tuning the
 schedule to the machine.  This module is that tuning knob taken one step
 further: a chunked, software-pipelined scheduler that interleaves the
-dimension-wise *rounds* of independent payload chunks with an optional
-per-chunk *compute stage*, so XLA's async collectives
-(``all-to-all-start``/``-done``) can hide the rounds behind consumer
-compute (MoE expert FFN, Ulysses attention) as well as behind each other.
+dimension-wise *rounds* of independent payload chunks with a per-chunk
+*compute stage*, so the rounds of one chunk can hide behind consumer
+compute of another (MoE expert FFN, Ulysses attention).
+
+Chunks exist for the compute stage alone, so the engine requires one.
+Without one, ``A2APlan`` runs the d rounds on the whole ``(p, *block)``
+buffer in one torus view (``factorized._factorized_impl``): chunking
+would only add the local slicing, re-tiling and concatenation that the
+zero-copy exchange avoids, and hide nothing: on the TPU v5e the
+all-to-alls are synchronous ops (no ``-start``/``-done`` pair) that run
+one after another, so the rounds of different chunks never overlap.
 
 Per chunk the stage list is::
 
-    [round k0, ..., round k_{d-1}]  (+ [compute])  (+ [rev k'0, ..., rev k'_{d-1}])
+    [round k0, ..., round k_{d-1}]  + [compute]  (+ [rev k'0, ..., rev k'_{d-1}])
 
 and the engine emits stage ``s`` of chunk ``c`` at pipeline step ``t = c +
 s``, deepest stage first within a step, i.e. the program order
@@ -20,17 +27,17 @@ s``, deepest stage first within a step, i.e. the program order
 
 Chunk ``c``'s stages depend only on chunk ``c``'s earlier stages, so every
 step's ops are mutually independent: adjacent in program order, they are
-exactly what XLA's latency-hiding scheduler overlaps.  On a d-dim torus the
-per-dimension rounds of different chunks use *different dimension links*,
-giving up to d-fold link-level overlap on top of the comm/compute overlap.
-Correctness is independent of scheduling — the interleaving only reorders
-independent ops (property- and parity-tested against ``factorized`` and
-``direct``).
+what XLA's latency-hiding scheduler may overlap where the collectives are
+asynchronous.  Correctness is independent of scheduling — the
+interleaving only reorders independent ops (property- and parity-tested
+against ``factorized`` and ``direct``).
 
-Cost model: see ``tuning.predict_overlapped`` — perfect overlap divides
-the bandwidth term by ~min(d, n_chunks) while stretching the latency term
+Cost model: see ``tuning.predict_overlapped`` — it assumes the rounds of
+different chunks run concurrently on different dimension links, dividing
+the bandwidth term by ~min(d, n_chunks), while the latency term stretches
 by the pipeline fill ``(d + n - 1)/d``; ``tuning.choose_chunks`` picks the
-argmin.
+argmin.  ``backend="tuned"`` still picks ``overlap`` by that price for a
+compute-free exchange, which then runs unchunked.
 """
 
 from __future__ import annotations
@@ -45,7 +52,6 @@ from jax import lax
 from .factorized import (
     _as_tuple,
     _axis_sizes,
-    _factorized_impl,
     _skip_trivial,
     _warn_deprecated,
 )
@@ -152,13 +158,17 @@ def _split_chunks(x, axis, n_chunks):
 # The overlapped all-to-all
 # ---------------------------------------------------------------------------
 
-def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
-                     variant: str = "natural", round_order=None,
-                     compute_fn: Callable | None = None,
-                     reverse: bool = False, reverse_round_order=None,
+def _overlapped_impl(x, axis_names, compute_fn: Callable, *,
+                     n_chunks: int = 2, variant: str = "natural",
+                     round_order=None, reverse: bool = False,
+                     reverse_round_order=None,
                      chunk_axis: int | None = None):
-    """Chunked, software-pipelined factorized all-to-all with an optional
-    per-chunk compute stage and reverse (combine) all-to-all.
+    """Chunked, software-pipelined factorized all-to-all with a per-chunk
+    compute stage and an optional reverse (combine) all-to-all.
+
+    Chunks exist to interleave rounds with the compute stage; without one
+    the caller runs the unchunked rounds (``A2APlan.forward`` /
+    ``.reverse``, ``factorized._factorized_impl``).
 
     Args:
       x: local ``(p, *block)`` array, ``p`` = product of the named axis
@@ -168,8 +178,8 @@ def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
         extent; 1 disables pipelining but still runs fwd/compute/reverse).
       variant: per-round formulation, "natural" (zero-copy) or "paper".
       round_order: forward round permutation (default ``range(d)``).
-      compute_fn: optional ``f(chunk, chunk_index) -> chunk`` applied to
-        each chunk *after* its forward rounds; must preserve the chunk's
+      compute_fn: ``f(chunk, chunk_index) -> chunk`` applied to each
+        chunk *after* its forward rounds; must preserve the chunk's
         shape.  Called on the ``(p, *chunk_block)`` layout.
       reverse: append a second (combine-direction) all-to-all after the
         compute stage — the MoE dispatch/combine shape.
@@ -177,8 +187,7 @@ def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
         (default: forward order reversed, so the pipeline drains the
         dimension links in the opposite order it filled them).
       chunk_axis: which axis of ``x`` (>= 1) to chunk.  Default: the
-        trailing payload is flattened and chunked (the
-        ``pipelined_all_to_all`` semantics).
+        trailing payload is flattened and chunked.
 
     Returns ``(p, *block)`` with the same semantics as composing
     ``factorized_all_to_all`` (+ ``compute_fn`` + ``factorized_all_to_all``)
@@ -194,12 +203,6 @@ def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
     order = _check_order(round_order, d)
     rev_order = (tuple(reversed(order)) if reverse_round_order is None
                  else _check_order(reverse_round_order, d))
-
-    # Fast path: nothing to pipeline and nothing to interleave.
-    if compute_fn is None and not reverse:
-        if d <= 1 or n_chunks <= 1 or x.ndim == 1:
-            return _factorized_impl(x, axis_names, variant=variant,
-                                    round_order=round_order)
 
     # ---- chunking ----
     if chunk_axis is None:
@@ -221,15 +224,13 @@ def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
     def to_blocks(view):
         return view.reshape((p,) + view.shape[d:])
 
+    def compute_stage(view, c):
+        return to_view(compute_fn(to_blocks(view), c))
+
     stages = list(_round_stages(names, sizes, variant, order))
-    if compute_fn is not None:
-        def compute_stage(view, c):
-            return to_view(compute_fn(to_blocks(view), c))
-        stages.append(compute_stage)
+    stages.append(compute_stage)
     if reverse:
         stages.extend(_round_stages(names, sizes, variant, rev_order))
-    if not stages:                       # d == 0 and no compute/reverse
-        return x
 
     views = run_pipelined([to_view(c) for c in chunks], stages)
     outs = [to_blocks(v) for v in views]
@@ -238,36 +239,6 @@ def _overlapped_impl(x, axis_names, *, n_chunks: int = 2,
         return out.reshape((p,) + x.shape[1:])
     return outs[0] if len(outs) == 1 else \
         jnp.concatenate(outs, axis=chunk_axis)
-
-
-def _overlapped_tiled_impl(x, axis_names, split_axis, concat_axis, *,
-                           n_chunks: int = 2, variant: str = "natural",
-                           round_order=None):
-    """Tiled-semantics overlapped all-to-all.
-
-    Drop-in for ``lax.all_to_all(..., tiled=True)`` /
-    ``_factorized_tiled_impl`` — the MoE-dispatch and Ulysses re-shard
-    form — with the payload chunked and the per-dimension rounds of
-    different chunks interleaved in program order.
-    """
-    axis_names = _as_tuple(axis_names)
-    dims = _axis_sizes(axis_names)
-    p = math.prod(dims)
-    if p == 1:
-        return x
-    S = x.shape[split_axis]
-    if S % p:
-        raise ValueError(f"split axis size {S} not divisible by p={p}")
-    shape = x.shape
-    xb = x.reshape(shape[:split_axis] + (p, S // p) + shape[split_axis + 1:])
-    xb = jnp.moveaxis(xb, split_axis, 0)
-    out = _overlapped_impl(xb, axis_names, n_chunks=n_chunks,
-                           variant=variant, round_order=round_order)
-    out = jnp.moveaxis(out, 0, concat_axis)
-    sh = out.shape
-    return out.reshape(sh[:concat_axis]
-                       + (sh[concat_axis] * sh[concat_axis + 1],)
-                       + sh[concat_axis + 2:])
 
 
 # ---------------------------------------------------------------------------
@@ -283,7 +254,7 @@ def overlapped_all_to_all(x, axis_names, *, n_chunks: int = 2,
                           reverse: bool = False, reverse_round_order=None,
                           chunk_axis: int | None = None):
     """Deprecated: use ``plan_all_to_all(..., backend="overlap")
-    .overlap`` (or ``.forward`` when there is no compute stage)."""
+    .overlap``."""
     _warn_deprecated("overlapped_all_to_all", "plan.overlap")
     from .plan import plan_all_to_all
     names = _as_tuple(axis_names)
@@ -292,8 +263,6 @@ def overlapped_all_to_all(x, axis_names, *, n_chunks: int = 2,
                            round_order=round_order,
                            reverse_round_order=reverse_round_order,
                            n_chunks=max(1, n_chunks))
-    if compute_fn is None and not reverse:
-        return plan.forward(x)
     return plan.overlap(x, compute_fn, reverse=reverse,
                         chunk_axis=chunk_axis)
 
@@ -314,13 +283,13 @@ def overlapped_all_to_all_tiled(x, axis_names, split_axis, concat_axis, *,
 
 def pipelined_all_to_all(x, axis_names, *, n_chunks: int = 2,
                          variant: str = "natural", round_order=None):
-    """Deprecated: use ``plan_all_to_all(..., backend="pipelined")
-    .forward`` — the chunk-interleaved schedule with no compute stage."""
+    """Deprecated: use ``plan_all_to_all(..., backend="factorized")
+    .forward``.  With no compute stage there is nothing to chunk for, so
+    ``n_chunks`` is ignored and the whole payload runs the d rounds."""
     _warn_deprecated("pipelined_all_to_all", "plan.forward")
     from .plan import plan_all_to_all
     names = _as_tuple(axis_names)
     plan = plan_all_to_all(_axis_sizes(names), names, x.shape[1:], x.dtype,
-                           backend="pipelined", variant=variant,
-                           round_order=round_order,
-                           n_chunks=max(1, n_chunks))
+                           backend="factorized", variant=variant,
+                           round_order=round_order)
     return plan.forward(x)

@@ -2,10 +2,11 @@
 
 The chunk-interleaved scheduler that used to live here has been absorbed
 into the general overlap engine (``core.overlap``), which adds arbitrary
-``round_order``, per-chunk compute stages, reverse (combine) rounds, and
-tiled semantics.  ``pipelined_all_to_all`` remains the no-compute-stage
-specialization and is re-exported here for existing callers; like every
-legacy free function it is now a ``DeprecationWarning`` shim over
+``round_order``, per-chunk compute stages and reverse (combine) rounds.
+``pipelined_all_to_all`` remains the no-compute-stage form, which runs the
+whole payload unchunked (chunks exist to interleave rounds with a compute
+stage), and is re-exported here for existing callers; like every legacy
+free function it is now a ``DeprecationWarning`` shim over
 ``core.plan.plan_all_to_all(..., backend="pipelined").forward``.
 
 ``choose_chunks`` now delegates to the tuning model's

@@ -10,9 +10,10 @@ knobs)`` key,
 
 * the torus factorization (``core.cache.get_factorization``, keyed by the
   stable ``(device.id, platform)`` fingerprint when a ``Mesh`` is given),
-* the backend — ``direct`` | ``factorized`` | ``pipelined`` | ``overlap``,
-  either requested explicitly or chosen by the alpha-beta cost model
-  (``backend="tuned"`` → ``tuning.choose_algorithm``/``choose_chunks``),
+* the backend — ``direct`` | ``factorized`` | ``overlap``, either
+  requested explicitly or chosen by the alpha-beta cost model
+  (``backend="tuned"`` → ``tuning.choose_algorithm``/``choose_chunks``);
+  ``pipelined`` is a deprecated alias of ``factorized``,
 * the per-round peer-axis sequence (forward and reverse/drain orders) and
   the payload chunk count,
 
@@ -76,7 +77,7 @@ from .factorized import (
     _factorized_tiled_impl,
     _skip_trivial,
 )
-from .overlap import _check_order, _overlapped_impl, _overlapped_tiled_impl
+from .overlap import _check_order, _overlapped_impl
 from .tuning import (
     DCN_AXES,            # noqa: F401  (re-exported; moved to core.tuning)
     LinkModel,
@@ -91,6 +92,7 @@ from .tuning import (
     slowest_active_link,
 )
 
+# "pipelined" is a deprecated alias of "factorized" (resolved in _resolve)
 BACKENDS = ("tuned", "autotune", "direct", "factorized", "pipelined",
             "overlap")
 
@@ -166,6 +168,20 @@ class A2APlan:
             return None
         return math.prod(self.block_shape) * jnp.dtype(self.dtype).itemsize
 
+    @property
+    def predicted_seconds(self) -> float | None:
+        """Model seconds of one ``forward`` / ``reverse``: what ``host_fn``
+        runs and what drift is measured against.  An ``overlap`` plan's
+        schedule prices the chunked rounds that ``overlap`` runs with a
+        compute stage; with none the rounds run unchunked, priced as
+        ``factorized``."""
+        if self.schedule is None:
+            return None
+        if self.backend == "overlap":
+            return predict_factorized(self.dims, self.links,
+                                      float(self.block_bytes), self.p)
+        return self.schedule.predicted_seconds
+
     # -- execution surface (inside shard_map) ------------------------------
 
     def forward(self, x):
@@ -183,16 +199,14 @@ class A2APlan:
         return self._run(x, self.rev_order)
 
     def _run(self, x, order):
+        # overlap too: with no compute stage there is nothing for chunks
+        # to hide behind, so the whole buffer runs the d rounds; chunks
+        # are for ``overlap`` with a compute stage (``core.overlap``).
         with _scope(self.backend):
             if self.backend == "direct":
                 return _direct_impl(x, self.axis_names)
-            if self.backend == "factorized":
-                return _factorized_impl(x, self.axis_names,
-                                        variant=self.variant,
-                                        round_order=order)
-            return _overlapped_impl(x, self.axis_names,
-                                    n_chunks=self.n_chunks,
-                                    variant=self.variant, round_order=order)
+            return _factorized_impl(x, self.axis_names, variant=self.variant,
+                                    round_order=order)
 
     def tiled(self, x, split_axis: int, concat_axis: int, *,
               reverse: bool = False):
@@ -204,15 +218,9 @@ class A2APlan:
             if self.backend == "direct":
                 return _direct_tiled_impl(x, self.axis_names, split_axis,
                                           concat_axis)
-            if self.backend == "factorized":
-                return _factorized_tiled_impl(
-                    x, self.axis_names, split_axis, concat_axis,
-                    variant=self.variant, round_order=order)
-            return _overlapped_tiled_impl(x, self.axis_names, split_axis,
-                                          concat_axis,
-                                          n_chunks=self.n_chunks,
-                                          variant=self.variant,
-                                          round_order=order)
+            return _factorized_tiled_impl(
+                x, self.axis_names, split_axis, concat_axis,
+                variant=self.variant, round_order=order)
 
     def overlap(self, x, compute_fn: Callable | None = None, *,
                 reverse: bool = True, chunk_axis: int | None = None):
@@ -220,10 +228,16 @@ class A2APlan:
         (``core.overlap``): chunk ``c``'s forward rounds are emitted next
         to chunk ``c-1``'s compute and chunk ``c-2``'s reverse rounds.
         Bit-exact with ``reverse(compute_fn(forward(x)))`` since chunks
-        never interact."""
-        return _overlapped_impl(x, self.axis_names, n_chunks=self.n_chunks,
+        never interact.  Without ``compute_fn`` there is nothing to hide
+        the rounds behind and nothing is chunked: ``forward``, then
+        ``reverse`` if asked."""
+        if compute_fn is None:
+            y = self.forward(x)
+            return self.reverse(y) if reverse else y
+        return _overlapped_impl(x, self.axis_names, compute_fn,
+                                n_chunks=self.n_chunks,
                                 variant=self.variant, round_order=self.order,
-                                compute_fn=compute_fn, reverse=reverse,
+                                reverse=reverse,
                                 reverse_round_order=self.rev_order,
                                 chunk_axis=chunk_axis)
 
@@ -238,9 +252,10 @@ class A2APlan:
 
         The returned callable checks the telemetry tracer per call: off
         (the default), it dispatches the cached fused jit directly; on,
-        factorized plans execute the *stepped* per-round path (one jitted
-        step per dimension-wise round — bit-exact, rounds commute) so
-        every round gets a measured span and a drift observation."""
+        every plan but a direct one executes the *stepped* per-round path
+        (one jitted step per dimension-wise round — bit-exact, rounds
+        commute) so every round gets a measured span and a drift
+        observation."""
         mesh = self._mesh if mesh is None else mesh
         if mesh is None:
             raise ValueError("plan was built without a Mesh; pass one")
@@ -287,7 +302,7 @@ class A2APlan:
 
     def _round_host_fns(self, mesh):
         """Per-round jitted host fns in forward round order — the
-        stepped traced path (factorized backend only)."""
+        stepped traced path (every backend but direct)."""
         if mesh not in self._round_fns:
             spec = P(tuple(reversed(self.axis_names)))
             names, sizes = _skip_trivial(self.axis_names, self.dims)
@@ -308,9 +323,7 @@ class A2APlan:
         det = telemetry.drift_detector()
         key = self._drift_key()
         preds = self._per_axis_predictions()
-        predicted = self.schedule.predicted_seconds \
-            if self.schedule is not None \
-            else (sum(preds.values()) if preds else None)
+        predicted = self.predicted_seconds
         telemetry.metrics().counter("plan.traced_executions").inc()
         # Installed fault injectors (core.faults) expose a per-round
         # guard so injected slow rounds land inside the round spans.
@@ -321,7 +334,7 @@ class A2APlan:
                      predicted_seconds=predicted, tuned_from=self.tuned_from,
                      drift_key=key) as ex:
             t0 = time.perf_counter()
-            if self.backend == "factorized":
+            if self.backend != "direct":
                 y = x
                 for k, name, Dk, fn in self._round_host_fns(mesh):
                     pred_k = None if preds is None else preds.get(name)
@@ -336,9 +349,8 @@ class A2APlan:
                             det.observe(f"{key}:axis={name}", pred_k,
                                         time.perf_counter() - tr0)
             else:
-                # direct = a single product-communicator round; overlap
-                # interleaves rounds across chunks — neither splits into
-                # host-steppable rounds, so one fused span covers them.
+                # direct = a single product-communicator round: it
+                # does not split into host-steppable rounds.
                 with tr.span("plan.round", cat="plan", axis="*",
                              backend=self.backend, timing="fused",
                              predicted_seconds=predicted):
@@ -355,7 +367,6 @@ class A2APlan:
 
     def describe(self) -> dict:
         """Stable, JSON-serializable summary of the resolved plan."""
-        sched = self.schedule
         return {
             "kind": "dense",
             "axis_names": list(self.axis_names),
@@ -368,13 +379,17 @@ class A2APlan:
             "round_order": list(self.order),
             "reverse_round_order": list(self.rev_order),
             "n_chunks": self.n_chunks,
+            # forward / reverse / tiled have no compute stage to hide
+            # rounds behind: one chunk, the whole buffer (``n_chunks`` is
+            # what ``overlap`` uses with a compute stage).  Held to the
+            # compiled program by tests/device_scripts/check_zero_copy.py.
+            "forward_chunks": 1,
             "block_shape": None if self.block_shape is None
             else list(self.block_shape),
             "dtype": None if self.dtype is None
             else jnp.dtype(self.dtype).name,
             "block_bytes": self.block_bytes,
-            "predicted_seconds": None if sched is None
-            else sched.predicted_seconds,
+            "predicted_seconds": self.predicted_seconds,
             "blocks_sent_per_device": self.fact.blocks_sent_per_device(),
             "links": [{"alpha": l.alpha, "bandwidth": l.bandwidth}
                       for l in self.links],
@@ -514,7 +529,10 @@ def _resolve(dims, axis_names, block_shape, dtype, requested_backend,
         return backend, order, rev_order, max(1, n), links, sched
 
     backend = requested_backend
-    n = n_chunks or (2 if backend in ("overlap", "pipelined") else 1)
+    if backend == "pipelined":
+        # deprecated alias: chunks without a compute stage hide nothing
+        backend, n_chunks = "factorized", 0
+    n = n_chunks or (2 if backend == "overlap" else 1)
     n = max(1, n)
     sched = None
     if block_bytes is not None:
@@ -560,12 +578,13 @@ def plan_all_to_all(mesh_or_axis_dims, axis_names, block_shape=None,
         from the persistent tuning DB — a hit rebuilds the recorded
         winner, a miss falls back to the cost model without measuring;
         see ``core.autotune``), or an explicit kernel:
-        "direct" | "factorized" | "pipelined" | "overlap".
+        "direct" | "factorized" | "overlap" ("pipelined": deprecated
+        alias of "factorized").
       variant: per-round formulation, "natural" (zero-copy) or "paper".
       round_order / reverse_round_order: permutations of the active rounds
         (default: identity, and its reversal for the drain direction).
-      n_chunks: payload chunks for the overlap engine; 0 = resolve (cost
-        model under "tuned", else 2).
+      n_chunks: payload chunks for ``overlap`` with a compute stage; 0 =
+        resolve (cost model under "tuned", else 2).
       max_chunks: search bound for the tuned chunk count.
       links: per-axis :class:`LinkModel` overrides (default: DCN for
         ``pod``-like axes, ICI otherwise; measured per-axis fits under a
@@ -704,7 +723,7 @@ class TransposePlan:
     metadata for that re-shard with an inner dense :class:`A2APlan` over
     the same torus whose per-peer block is one chunk, so the transpose
     resolves through any dense backend — ``direct`` / ``factorized`` /
-    ``pipelined`` / ``overlap`` / ``tuned`` / ``autotune`` — and shares
+    ``overlap`` / ``tuned`` / ``autotune`` — and shares
     the registry, cost model, tuning DB, and telemetry machinery.
 
     Correctness oracle: ``core.simulator.simulate_pencil_transpose``.
@@ -825,8 +844,8 @@ class TransposePlan:
         returns it re-sharded per ``out_spec``.  Defaults to
         :meth:`specs`.  Like ``A2APlan.host_fn`` the callable is
         tracer-aware: tracing off dispatches one fused jit; tracing on
-        runs the stepped per-round path (factorized backend) so every
-        dimension-wise round gets a measured span and a drift
+        runs the stepped per-round path (every backend but direct) so
+        every dimension-wise round gets a measured span and a drift
         observation."""
         mesh = self.inner._mesh if mesh is None else mesh
         if mesh is None:
@@ -893,11 +912,9 @@ class TransposePlan:
         det = telemetry.drift_detector()
         key = self._drift_key()
         preds = self.inner._per_axis_predictions()
-        sched = self.inner.schedule
-        predicted = sched.predicted_seconds if sched is not None \
-            else (sum(preds.values()) if preds else None)
+        predicted = self.inner.predicted_seconds
         telemetry.metrics().counter("plan.traced_executions").inc()
-        stepped = (self.backend == "factorized"
+        stepped = (self.backend != "direct"
                    and set(self.axis_names) == set(mesh.axis_names))
         with tr.span("plan.execute", cat="plan", kind="transpose",
                      backend=self.backend,

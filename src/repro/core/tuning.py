@@ -177,14 +177,21 @@ def predict_overlapped(dims, links, block_bytes: float, p: int,
     (``core.overlap``).
 
     Splitting the block payload into ``n`` chunks and interleaving the
-    per-chunk round schedules lets rounds of different chunks run on
-    *different dimension links* concurrently: in steady state the
-    bandwidth term is divided by the achievable concurrency
+    per-chunk round schedules is modelled as letting rounds of different
+    chunks run on *different dimension links* concurrently: in steady
+    state the bandwidth term is divided by the achievable concurrency
     ``min(d, n)``.  The price is the pipeline fill/drain — each round's
     per-peer latency is paid ``(d + n - 1)/d`` times over the schedule —
     so the latency term *grows monotonically* in ``n`` while the
     bandwidth term shrinks, reproducing the small-vs-large payload
     crossover the paper observes for direct-vs-factorized one level up.
+
+    The link-level concurrency assumes asynchronous collectives.  On the
+    TPU v5e trace the all-to-alls are synchronous ops that run one after
+    another, so chunked rounds alone hide nothing there.  Chunks exist to
+    interleave rounds with a compute stage (``A2APlan.overlap``); a
+    compute-free ``overlap`` / ``pipelined`` exchange runs unchunked,
+    whatever ``n`` this model picks.
 
     ``compute_seconds`` models an interleaved per-chunk compute stage
     (MoE expert FFN, Ulysses attention): with ``n`` chunks all but the

@@ -94,7 +94,8 @@ class ModelConfig:
     use_ulysses: bool = False         # Ulysses SP for attention
     expert_axes: tuple[str, ...] = ("data",)   # EP mesh axes (fastest first)
     a2a_variant: str = "natural"      # factorized A2A variant for EP/SP
-    # tuned | autotune | factorized | direct | pipelined | overlap
+    # tuned | autotune | factorized | direct | overlap (pipelined: a
+    # deprecated alias of factorized)
     # "overlap" pipelines dispatch-round / expert-FFN / combine-round per
     # payload chunk (core.overlap); "tuned" picks backend AND chunk count
     # from the alpha-beta model (tuning.choose_algorithm); "autotune"

@@ -270,12 +270,14 @@ def _moe_inner(x, router_w, w1, w3, w2, *, cfg: ModelConfig, axes, G, E_loc,
     # chunk count, and round orders were all fixed once at plan time
     # (tuning.choose_algorithm prices tuned|direct|factorized|overlap with
     # per-axis ICI/DCN links); here we only replay the chosen kernel. ----
+    # The (G, E_loc, C, D) blocks go in unflattened: in a compile for a
+    # described TPU v5e their torus view (D_{d-1}, ..., D_0, E_loc, C, D)
+    # is a bitcast, where a flat (G, E_loc*C*D) operand is re-tiled into
+    # it and back (no chip run covers this path yet).
     def a2a(blocks, reverse=False):
         if plan is None:
             return blocks
-        flat = blocks.reshape(G, -1)
-        out = plan.reverse(flat) if reverse else plan.forward(flat)
-        return out.reshape(blocks.shape)
+        return plan.reverse(blocks) if reverse else plan.forward(blocks)
 
     if ragged_plan is not None:
         # Dropless (capacity_factor=None): the ragged Alltoallv moves the

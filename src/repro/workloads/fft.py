@@ -8,8 +8,8 @@ so the next axis becomes local.  Every transpose is an all-to-all of one
 contiguous pencil chunk per peer — exactly the paper's factorized
 zero-copy collective — so here each transpose is a cached
 :class:`~repro.core.plan.TransposePlan` resolved through any dense
-backend (``direct`` / ``factorized`` / ``pipelined`` / ``overlap`` /
-``tuned`` / ``autotune``).
+backend (``direct`` / ``factorized`` / ``overlap`` / ``tuned`` /
+``autotune``).
 
 Decomposition model
 -------------------

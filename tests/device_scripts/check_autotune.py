@@ -61,8 +61,9 @@ def main():
     np.testing.assert_array_equal(got, expected)
     np.testing.assert_array_equal(ref, expected)
     table = plan.describe()["measured"]["table"]
-    assert {r["backend"] for r in table} >= {"direct", "factorized",
-                                             "overlap"}, table
+    # a compute-free overlap plan runs the factorized rounds unchunked,
+    # so the search times no overlap candidate
+    assert {r["backend"] for r in table} == {"direct", "factorized"}, table
     assert any(not r["eligible"] for r in table), \
         "factorization sweep rows missing"
     print(f"OK autotuned == analytic bit-exact "

@@ -3,7 +3,7 @@
 Checks, for a sweep of factorizations/variants/round orders:
   * factorized == direct collective == all-to-all semantics (out[r,i] = x[i,r])
   * the paper-literal and natural variants agree
-  * pipelined (chunk-overlapped) variant agrees
+  * pipelined (a deprecated alias of factorized) agrees
   * tiled semantics == lax tiled collective
   * dtype coverage: f32, bf16, i32, f16
 Exits nonzero on any mismatch.

@@ -178,6 +178,14 @@ def main():
     x2 = jnp.arange(12 * 12 * 4, dtype=jnp.int32).reshape(12, 12, 4)
     check_span_coverage(tr, plan2, x2, ("i", "j"))
 
+    # an overlap plan's compute-free exchange runs the same unchunked
+    # rounds, so it steps them too, priced as factorized
+    plan2o = comm2.all_to_all(block_shape=(4,), dtype=jnp.int32,
+                              backend="overlap", n_chunks=2)
+    check_span_coverage(tr, plan2o, x2, ("i", "j"))
+    assert plan2o.predicted_seconds == plan2.predicted_seconds
+    print("OK overlap plan steps its rounds")
+
     # d=3: a 2x2x3 torus through the plan factory
     mesh3 = cart_create(12, (2, 2, 3), ("x", "y", "z"))
     plan3 = plan_all_to_all(mesh3, ("x", "y", "z"), backend="factorized",

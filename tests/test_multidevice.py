@@ -22,6 +22,19 @@ def test_zero_copy_hlo():
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("operand", ["flat", "blocks"])
+@pytest.mark.parametrize("backend", ["overlap", "pipelined"])
+def test_zero_copy_compute_free_exchange(backend, operand):
+    # overlap / pipelined forward and reverse with no compute stage: d
+    # all-to-alls per direction, as many as describe()["forward_chunks"]
+    # (1) says, and no chunk slicing or concatenation under the plan's
+    # a2a[<backend>] scope, on a 2x2 and a 2x3 torus.
+    out = run_device_script("check_zero_copy.py", 6, "--backend", backend,
+                            "--operand", operand)
+    assert out.count(f"OK compute-free {backend} {operand}") == 2
+
+
+@pytest.mark.slow
 def test_plan_equivalence_12dev():
     # A2APlan.forward/reverse/tiled/overlap bit-exact with the legacy free
     # functions across backends x variants x round orders, shims warn, and
@@ -192,7 +205,8 @@ def test_pencil_fft_12dev():
 @pytest.mark.slow
 def test_telemetry_12dev(tmp_path):
     # Telemetry spine acceptance: with tracing on, factorized plans on
-    # d=2 (3x4) and d=3 (2x2x3) tori execute the stepped per-round path
+    # d=2 (3x4) and d=3 (2x2x3) tori, and an overlap plan on the 3x4
+    # torus, execute the stepped per-round path
     # bit-exact with the fused jit, recording one plan.round span per
     # dimension-wise round per execution; unified_stats() returns the
     # merged MetricsRegistry snapshot; an injected FaultSpec slow round
@@ -201,6 +215,7 @@ def test_telemetry_12dev(tmp_path):
     trace = tmp_path / "trace.json"
     out = run_device_script("check_telemetry.py", 12, str(trace))
     assert "OK span coverage d=2 (3x4): 3 executions x 2 rounds" in out
+    assert "OK overlap plan steps its rounds" in out
     assert "OK span coverage d=3 (2x2x3): 3 executions x 3 rounds" in out
     assert "OK unified snapshot" in out
     assert "OK drift retune" in out
